@@ -325,11 +325,8 @@ def run_fault_backend(model_name: str = "alexnet", pop: int = 60,
     # fault state (the HBM footprint), with the raw compiled peak
     # alongside — see the docstring for why the peak is not compared
     def io_bytes(compiled):
-        try:
-            m = compiled.memory_analysis()
-        except Exception:
-            return 0
-        return sum(int(getattr(m, f, 0) or 0) for f in
+        m = compiled.memory_analysis()    # a backend without one fails here
+        return sum(int(getattr(m, f)) for f in
                    ("argument_size_in_bytes", "output_size_in_bytes"))
 
     seed32 = jnp.int32(0)
@@ -748,6 +745,8 @@ def run_lm_generational(arch: str = "olmo-1b", pop: int = 24,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(__file__)
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--model", default="alexnet",
                     choices=["alexnet", "squeezenet", "resnet18"])

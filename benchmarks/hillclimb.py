@@ -109,6 +109,8 @@ def run_eval_engine(model: str, pop: int, eval_batch_size: int | None):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(__file__)
     ap = argparse.ArgumentParser()
     ap.add_argument("--target", default="roofline",
                     choices=["roofline", "eval-engine"])

@@ -418,6 +418,8 @@ def _optional_value(flag: str) -> str | None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(__file__)
     print("# benchmark,us_per_call,derived")
     if any(a == "--surrogate" or a.startswith("--surrogate=")
            for a in sys.argv):

@@ -58,12 +58,13 @@ from __future__ import annotations
 
 import os
 from collections import OrderedDict
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Sequence
 
 import numpy as np
 
 __all__ = ["PopulationEvalEngine", "PrefixEvalEngine", "ActivationStore",
-           "DeviceScheduler", "PrefixRef", "StackedView",
+           "DeviceScheduler", "DeviceStreams", "PrefixRef", "StackedView",
            "chunked_rows", "bucket_size", "pad_rows",
            "auto_eval_batch_size", "device_memory_budget",
            "peak_memory_bytes", "parse_eval_batch_size", "parse_devices"]
@@ -153,6 +154,39 @@ class DeviceScheduler:
         if device is None:
             return jnp.asarray(array)
         return jax.device_put(array, device)
+
+
+class DeviceStreams:
+    """One host thread per device group of a dispatch wave.
+
+    jit compiles an executable once per committed device, so the first
+    dispatch of each executable on each device compiles, and a host
+    thread that dispatches to the pool's devices one after another pays
+    N compiles in a row.  XLA compiles for different devices proceed in
+    parallel; with one worker thread per group, N devices pay about one
+    compile's wall time.  Each group's calls keep their order (one
+    worker per group).  Once compiled, a call is an asynchronous
+    dispatch either way.  With one group, calls run on the calling
+    thread, exactly as without streams.
+    """
+
+    def __init__(self, n_groups: int):
+        self._workers = [ThreadPoolExecutor(1) for _ in range(n_groups)] \
+            if n_groups > 1 else None
+
+    def submit(self, group: int, fn: Callable, *args, **kwargs) -> Future:
+        if self._workers is not None:
+            return self._workers[group].submit(fn, *args, **kwargs)
+        done: Future = Future()
+        done.set_result(fn(*args, **kwargs))
+        return done
+
+    def __enter__(self) -> "DeviceStreams":
+        return self
+
+    def __exit__(self, *exc):
+        for w in self._workers or ():
+            w.shutdown()
 
 
 def bucket_size(n: int) -> int:
@@ -638,13 +672,12 @@ class PrefixEvalEngine:
                     by_dev.setdefault(self._device_index(p), []).append(p)
                 groups = [(d, by_dev[d]) for d in sorted(by_dev)]
             pin = set(prefixes)
-            for dev_idx, group in groups:
-                parents = None if i == 0 else \
-                    [self._parent_for(p[:-1]) for p in group]
-                devs = np.array([[p[-1]] for p in group], np.int64)
-                outs = self._dispatch_group(
-                    self.unit_fns[i], parents, devs, final=last,
-                    dev_idx=dev_idx, unit_axis=False)
+            jobs = [(None if i == 0 else
+                     [self._parent_for(p[:-1]) for p in group],
+                     np.array([[p[-1]] for p in group], np.int64), dev_idx)
+                    for dev_idx, group in groups]
+            for (_, group), outs in zip(groups, self._dispatch_groups(
+                    self.unit_fns[i], jobs, final=last, unit_axis=False)):
                 if last:
                     pending.append((group, outs))
                 else:
@@ -671,27 +704,30 @@ class PrefixEvalEngine:
         # start-1) has start' < start, so dependencies are satisfied
         order = sorted(groups, key=lambda t: (
             t[0], t[1], -1 if t[2] is None else t[2]))
-        for key in order:
-            start, length, dev_idx = key
-            segs = groups[key]
+        waves: dict[tuple, list] = {}      # (start, length) -> groups
+        for start, length, dev_idx in order:
+            waves.setdefault((start, length), []).append(
+                (dev_idx, groups[(start, length, dev_idx)]))
+        for (start, length), wave in waves.items():
             final = start + length == L
-            fn = self.segment_fn(start, length)
-            parents = None if start == 0 else \
-                [self._parent_for(s[2]) for s in segs]
-            genes = np.array([s[3] for s in segs], np.int64)  # [U, length]
-            outs = self._dispatch_group(fn, parents, genes, final=final,
-                                        dev_idx=dev_idx, unit_axis=True)
-            keys = [s[2] + s[3] for s in segs]     # segment end prefixes
-            if final:
-                pending.append((keys, outs))
-            else:
-                # pin only the keys being stored (the depth walk's
-                # semantics): an evicted parent re-enters through the
-                # recompute fallback, so a tight budget stays tight
-                # instead of pinning every pending parent
-                self._store_group(keys, outs, set(keys))
-            self.unit_runs += len(segs) * length
-            self.fused_segments += len(segs)
+            jobs = [(None if start == 0 else
+                     [self._parent_for(s[2]) for s in segs],
+                     np.array([s[3] for s in segs], np.int64),  # [U, length]
+                     dev_idx)
+                    for dev_idx, segs in wave]
+            for (_, segs), outs in zip(wave, self._dispatch_groups(
+                    self.segment_fn(start, length), jobs, final=final)):
+                keys = [s[2] + s[3] for s in segs]  # segment end prefixes
+                if final:
+                    pending.append((keys, outs))
+                else:
+                    # pin only the keys being stored (the depth walk's
+                    # semantics): an evicted parent re-enters through the
+                    # recompute fallback, so a tight budget stays tight
+                    # instead of pinning every pending parent
+                    self._store_group(keys, outs, set(keys))
+                self.unit_runs += len(segs) * length
+                self.fused_segments += len(segs)
         self._gather_final(pending)
 
     def _plan_segments(self, rows: list) -> list:
@@ -875,9 +911,9 @@ class PrefixEvalEngine:
         devs = np.array([[prefix[-1]]], np.int64)
         dev_idx = None if self._multi() is None else \
             self._device_index(prefix)
-        outs = self._dispatch_group(self.unit_fns[i], parents, devs,
-                                    final=False, dev_idx=dev_idx,
-                                    unit_axis=False)
+        outs, = self._dispatch_groups(self.unit_fns[i],
+                                      [(parents, devs, dev_idx)],
+                                      final=False, unit_axis=False)
         batch, _ = outs[0]
         act = jax.tree.map(lambda a: a[0], batch.tree)
         self.unit_runs += 1
@@ -902,43 +938,70 @@ class PrefixEvalEngine:
         mats = [self._materialize(p) for p in chunk]
         return jax.tree.map(lambda *xs: jnp.stack(xs), *mats)
 
-    def _dispatch_group(self, fn: Callable, parents: list | None,
-                        genes: np.ndarray, final: bool,
-                        dev_idx: int | None = None,
-                        unit_axis: bool = True) -> list:
+    def _dispatch_groups(self, fn: Callable, jobs: list, final: bool,
+                         unit_axis: bool = True) -> list:
         """Chunked shape-bucketed dispatches of one unit or fused
-        segment over its ``[U, length]`` gene rows.  Non-final
-        dispatches return ``(_StackedBatch, n)`` per chunk (callers
-        store per-row views — no per-row unstack dispatches); the final
-        depth returns the un-synced ``(chunk_result, n)`` pairs the
-        caller converts after every dispatch has been issued.
+        segment, for each device group ``(parents, genes, dev_idx)`` of
+        ``jobs``; returns one list of chunk outputs per group.
+        Non-final dispatches give ``(_StackedBatch, n)`` per chunk
+        (callers store per-row views — no per-row unstack dispatches);
+        the final depth gives the un-synced ``(chunk_result, n)`` pairs
+        the caller converts after every dispatch has been issued.
         ``dev_idx`` commits the chunk inputs to that scheduler device;
         parents are resident there already (prefix-group invariant).
         ``unit_axis=False`` strips the per-unit gene axis for the
-        single-unit ``unit_fns`` contract (``devs: [U]``)."""
+        single-unit ``unit_fns`` contract (``devs: [U]``).
+
+        Chunk inputs are assembled on this thread (the store is not
+        thread-safe) round-robin over the groups; each group's calls go
+        to its own :class:`DeviceStreams` worker, so first-call compiles
+        on different devices overlap.  A group keeps at most one call
+        in flight, so at most one assembled chunk per device waits
+        beyond what the single-device path holds."""
         import jax
 
-        device = None if dev_idx is None else self.scheduler.devices[dev_idx]
-        outs: list = []
-        for start, stop, padded in chunked_rows(len(genes),
-                                                self.eval_batch_size):
-            g = np.asarray(pad_rows(genes[start:stop], padded), np.int32)
-            g_c = DeviceScheduler.put(g if unit_axis else g[:, 0], device)
-            acts = None if parents is None else \
-                self._stack_chunk(parents[start:stop], padded)
-            out = fn(acts, g_c)
-            self.dispatches += 1
-            if dev_idx is not None:
-                self.device_dispatches[dev_idx] = \
-                    self.device_dispatches.get(dev_idx, 0) + 1
-            n = stop - start
+        plans = [list(chunked_rows(len(genes), self.eval_batch_size))
+                 for _, genes, _ in jobs]
+        outs: list[list] = [[] for _ in jobs]
+        flight: list = [None] * len(jobs)    # (future, n, padded) per group
+
+        def land(j: int):
+            fut, n, padded = flight[j]
+            out = fut.result()
+            flight[j] = None
             if final:
-                outs.append((out, n))
+                outs[j].append((out, n))
             else:
                 if n < padded:      # drop padding rows: one slice per
                                     # chunk, keeps view accounting exact
                     out = jax.tree.map(lambda a: a[:n], out)
-                outs.append((_StackedBatch(out, n), n))
+                outs[j].append((_StackedBatch(out, n), n))
+
+        with DeviceStreams(len(jobs)) as streams:
+            for k in range(max(map(len, plans), default=0)):
+                for j, (parents, genes, dev_idx) in enumerate(jobs):
+                    if k >= len(plans[j]):
+                        continue
+                    start, stop, padded = plans[j][k]
+                    device = None if dev_idx is None \
+                        else self.scheduler.devices[dev_idx]
+                    g = np.asarray(pad_rows(genes[start:stop], padded),
+                                   np.int32)
+                    g_c = DeviceScheduler.put(g if unit_axis else g[:, 0],
+                                              device)
+                    acts = None if parents is None else \
+                        self._stack_chunk(parents[start:stop], padded)
+                    if flight[j] is not None:
+                        land(j)
+                    flight[j] = (streams.submit(j, fn, acts, g_c),
+                                 stop - start, padded)
+                    self.dispatches += 1
+                    if dev_idx is not None:
+                        self.device_dispatches[dev_idx] = \
+                            self.device_dispatches.get(dev_idx, 0) + 1
+            for j in range(len(jobs)):
+                if flight[j] is not None:
+                    land(j)
         return outs
 
 
@@ -993,18 +1056,23 @@ class PopulationEvalEngine:
                 # evenly over the pool
                 ebs = -(-len(rows) // sched.n_devices)
             pending = []
-            for ci, (start, stop, padded) in enumerate(
-                    chunked_rows(len(rows), ebs)):
-                chunk = pad_rows(rows[start:stop], padded)
-                if sched is not None:
-                    val = self.batch_fn(chunk, device=sched.device_for(ci))
-                else:
-                    val = self.batch_fn(chunk)
-                self.dispatches += 1
-                self.rows_evaluated += stop - start
-                pending.append((fresh_keys[start:stop], val, stop - start))
+            n_dev = 1 if sched is None else sched.n_devices
+            with DeviceStreams(n_dev) as streams:   # a stream per device
+                for ci, (start, stop, padded) in enumerate(
+                        chunked_rows(len(rows), ebs)):
+                    chunk = pad_rows(rows[start:stop], padded)
+                    if sched is not None:
+                        val = streams.submit(ci % n_dev, self.batch_fn,
+                                             chunk,
+                                             device=sched.device_for(ci))
+                    else:
+                        val = streams.submit(0, self.batch_fn, chunk)
+                    self.dispatches += 1
+                    self.rows_evaluated += stop - start
+                    pending.append((fresh_keys[start:stop], val,
+                                    stop - start))
             for chunk_keys, val, n in pending:   # once-per-call gather
-                vals = np.asarray(val)
+                vals = np.asarray(val.result())
                 for k, v in zip(chunk_keys, vals[:n]):
                     self._cache[k] = float(v)
         return np.array([self._cache[k] for k in keys])
@@ -1017,10 +1085,10 @@ class PopulationEvalEngine:
 def peak_memory_bytes(compiled) -> int:
     """Peak device bytes of an AOT-compiled executable, falling back to
     argument+output+temp when the backend does not report a peak (the
-    same fields launch/dryrun.py records per arch x shape cell)."""
-    try:
-        mem = compiled.memory_analysis()
-    except Exception:
+    same fields launch/dryrun.py records per arch x shape cell).  0 when
+    the backend reports no memory analysis at all."""
+    mem = compiled.memory_analysis()
+    if mem is None:
         return 0
     peak = int(getattr(mem, "peak_memory_in_bytes", 0) or 0)
     if peak:
@@ -1040,20 +1108,22 @@ def device_memory_budget(default: int = 2 << 30, n_devices: int = 1) -> int:
     backend) divided by ``n_devices``, because fake host devices
     (``--xla_force_host_platform_device_count``) share the one RAM pool
     -> ``default / n_devices``.  With the default ``n_devices=1`` this
-    is exactly the historical global budget.
+    is exactly the historical global budget.  A TPU that reports no
+    ``bytes_limit`` is an error: host RAM says nothing about HBM.
     """
+    import jax
+
     n_devices = max(1, int(n_devices))
     env = os.environ.get("REPRO_EVAL_MEM_BUDGET")
     if env:
         return int(env)
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit
-    except Exception:
-        pass
+    dev = jax.local_devices()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit > 0:
+        return limit
+    if dev.platform == "tpu":
+        raise RuntimeError(f"{dev.device_kind} reports no bytes_limit in "
+                           "memory_stats(); set REPRO_EVAL_MEM_BUDGET")
     try:
         pages = os.sysconf("SC_PHYS_PAGES")
         page = os.sysconf("SC_PAGE_SIZE")

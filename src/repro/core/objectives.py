@@ -779,24 +779,23 @@ class InferenceAccuracyEvaluator:
             return None
 
         def probe(n: int) -> int:
-            try:
-                if self._fault_backend == "pallas":
-                    D = len(self.w_rates_by_device)
-                    zd = jnp.zeros((D,), jnp.float32)
-                    compiled = self._ensure_pallas_batch().lower(
-                        jnp.zeros((n, L), jnp.int32), zd, zd,
-                        jnp.int32(self.base_seed)).compile()
-                elif self._fault_backend == "tables" \
-                        and self._acc_batch_tables is not None:
-                    compiled = self._acc_batch_tables.lower(
-                        jnp.zeros((n, L), jnp.int32),
-                        jnp.int32(self.base_seed)).compile()
-                else:
-                    z = jnp.zeros((n, L), jnp.float32)
-                    compiled = self._acc_batch.lower(
-                        z, z, jnp.int32(self.base_seed)).compile()
-            except Exception:
-                return 0
+            # a compile error here is the chip's compiler refusing the
+            # executable that would dispatch: let it propagate
+            if self._fault_backend == "pallas":
+                D = len(self.w_rates_by_device)
+                zd = jnp.zeros((D,), jnp.float32)
+                compiled = self._ensure_pallas_batch().lower(
+                    jnp.zeros((n, L), jnp.int32), zd, zd,
+                    jnp.int32(self.base_seed)).compile()
+            elif self._fault_backend == "tables" \
+                    and self._acc_batch_tables is not None:
+                compiled = self._acc_batch_tables.lower(
+                    jnp.zeros((n, L), jnp.int32),
+                    jnp.int32(self.base_seed)).compile()
+            else:
+                z = jnp.zeros((n, L), jnp.float32)
+                compiled = self._acc_batch.lower(
+                    z, z, jnp.int32(self.base_seed)).compile()
             return peak_memory_bytes(compiled)
 
         reserved = self.max_store_bytes or 0 \

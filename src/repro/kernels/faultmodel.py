@@ -67,7 +67,9 @@ def uniform01(idx: jax.Array, seed: jax.Array, plane: int) -> jax.Array:
     (element idx, seed, bit plane).  idx is uint32."""
     h = lowbias32(idx + jnp.uint32(plane * GOLDEN & 0xFFFFFFFF))
     u = lowbias32(h ^ seed.astype(jnp.uint32))
-    return (u >> 8).astype(jnp.float32) * INV24
+    # Mosaic has no uint32 -> float32 cast; the value is below 2**24, so
+    # going through int32 is exact and keeps kernels and refs identical.
+    return (u >> 8).astype(jnp.int32).astype(jnp.float32) * INV24
 
 
 def fault_mask(idx: jax.Array, seed: jax.Array, rate: jax.Array,

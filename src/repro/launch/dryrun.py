@@ -1,5 +1,7 @@
 import os
+# 512 fake CPU devices; on a machine with a TPU this tool must not take it
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # --- everything below may import jax ---------------------------------------
 import argparse

@@ -8,9 +8,18 @@ single CPU device; only the dry-run sets
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_eval_mesh",
            "mesh_axes"]
+
+
+def _auto_mesh(shape, axes, **kw):
+    """``jax.make_mesh`` with ``Auto`` axes: the model code places
+    activations with ``with_sharding_constraint``, which ``Explicit``
+    axes (the default since jax 0.7) reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,12 +28,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis carries the AFarePart pipeline stages."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over the real local device(s) for CPU tests."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_eval_mesh(n_devices: int):
@@ -36,8 +45,8 @@ def make_eval_mesh(n_devices: int):
     so the device list is passed explicitly; the mesh is the one
     agreement between the eval engines and the launch stack on device
     order."""
-    return jax.make_mesh((n_devices, 1), ("data", "model"),
-                         devices=jax.local_devices()[:n_devices])
+    return _auto_mesh((n_devices, 1), ("data", "model"),
+                      devices=jax.local_devices()[:n_devices])
 
 
 def mesh_axes(mesh) -> tuple[str, ...]:

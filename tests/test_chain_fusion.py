@@ -514,6 +514,7 @@ print("ALL-OK")
 def test_fused_sharded_matches_single_device_bitwise_subprocess():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"          # fake host devices, never a chip
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", _DIFF_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=1500)

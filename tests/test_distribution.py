@@ -12,7 +12,7 @@ import textwrap
 import pytest
 
 _ENV = {**os.environ, "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-        "PYTHONPATH": "src"}
+        "JAX_PLATFORMS": "cpu", "PYTHONPATH": "src"}
 
 
 def _run(code: str):

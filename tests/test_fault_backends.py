@@ -5,8 +5,8 @@ changing ``device_fault_scale`` must not rebuild or recompile anything.
 
 On CPU CI the pallas backend's ``ops.fault_matmul`` runs the exact
 interpret-mode composition (see kernels/ops.py), which is what makes
-the pin bitwise here; on a real TPU the fused tile holds under the
-kernel tolerance tests instead.
+the pin bitwise here; on a TPU the fused tile agrees within the
+tolerance that ``chip_smoke.py`` checks on the chip.
 """
 import dataclasses
 
@@ -38,11 +38,19 @@ def _clean_argmax_labels(apply_fn, params, x, n_units):
     return jnp.argmax(apply_fn(params, x, z, z, 0), axis=-1)
 
 
-# init keys chosen so the random-init model does NOT collapse to one
-# dominant class on the probe batch (a collapsed head keeps its argmax
-# under corruption — ΔAcc would be identically zero and the bitwise
-# pin vacuous)
-_INIT_KEY = {"alexnet": 0, "squeezenet": 4, "resnet18": 3}
+def _spread_init(model, x):
+    """Params and clean labels from the first init key whose model does
+    NOT collapse to one class on the probe batch.  A collapsed head keeps
+    its argmax under corruption, so ΔAcc would be identically zero and
+    the bitwise pin vacuous.  Searching, rather than naming a key, keeps
+    the setup valid when JAX changes its PRNG stream."""
+    for key in range(16):
+        params = model.init(jax.random.PRNGKey(key), num_classes=8,
+                            width=0.25, img=16)
+        labels = _clean_argmax_labels(model.apply, params, x, model.n_units)
+        if np.unique(np.asarray(labels)).size > 1:
+            return params, labels
+    raise AssertionError("every init key collapses to one class")
 
 
 @pytest.fixture(scope="module")
@@ -51,10 +59,8 @@ def cnn_setups():
     out = {}
     for name in CNN_MODELS:
         model = CNN_MODELS[name]
-        params = model.init(jax.random.PRNGKey(_INIT_KEY.get(name, 0)),
-                            num_classes=8, width=0.25, img=16)
         x = jnp.asarray(rng.normal(size=(8, 16, 16, 3)).astype(np.float32))
-        labels = _clean_argmax_labels(model.apply, params, x, model.n_units)
+        params, labels = _spread_init(model, x)
         P = rng.integers(0, len(SCALE), size=(10, model.n_units))
         out[name] = (model, params, x, labels, P)
     return out

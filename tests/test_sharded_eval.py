@@ -29,7 +29,8 @@ import numpy as np
 import pytest
 
 from repro.core.eval_engine import (ActivationStore, DeviceScheduler,
-                                    PopulationEvalEngine, PrefixEvalEngine,
+                                    DeviceStreams, PopulationEvalEngine,
+                                    PrefixEvalEngine,
                                     PrefixRef, auto_eval_batch_size,
                                     device_memory_budget, parse_devices)
 
@@ -86,6 +87,23 @@ def test_device_memory_budget_per_device(monkeypatch):
     assert device_memory_budget(n_devices=8) == 123456
 
 
+def test_device_memory_budget_tpu_without_limit_raises(monkeypatch):
+    """Host RAM is no stand-in for HBM: a TPU that reports no
+    ``bytes_limit`` must fail loudly, not budget from the host."""
+    import jax
+
+    class _Tpu:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+        def memory_stats(self):
+            return {}
+
+    monkeypatch.delenv("REPRO_EVAL_MEM_BUDGET", raising=False)
+    monkeypatch.setattr(jax, "local_devices", lambda: [_Tpu()])
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        device_memory_budget()
+
+
 def test_auto_eval_batch_size_per_device(monkeypatch):
     probe = lambda n: 1000 + 100 * n            # fixed 1000 + 100/row
     # an explicit budget is the caller's per-device number: n_devices
@@ -115,6 +133,31 @@ class _StubScheduler:
 
     def device_for(self, i):
         return self.devices[i % len(self.devices)]
+
+
+def test_device_streams_thread_per_group_in_order():
+    """One group runs on the calling thread; with several, each group
+    gets one worker thread of its own and keeps its submission order."""
+    import threading
+
+    main = threading.get_ident()
+    with DeviceStreams(1) as one:
+        assert one.submit(0, threading.get_ident).result() == main
+    seen = {g: [] for g in range(3)}
+
+    def call(g, k):
+        seen[g].append(k)
+        return threading.get_ident()
+
+    with DeviceStreams(3) as streams:
+        futs = {(g, k): streams.submit(g, call, g, k)
+                for k in range(5) for g in range(3)}
+    idents = {gk: f.result() for gk, f in futs.items()}
+    assert all(seen[g] == list(range(5)) for g in range(3))
+    assert main not in idents.values()
+    per_group = [{idents[(g, k)] for k in range(5)} for g in range(3)]
+    assert all(len(t) == 1 for t in per_group)
+    assert len(set.union(*per_group)) == 3
 
 
 def test_population_engine_splits_across_pool_bitwise():
@@ -352,6 +395,7 @@ print("ALL-OK")
 def test_sharded_matches_single_device_bitwise_subprocess():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"          # fake host devices, never a chip
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", _DIFF_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=1500)

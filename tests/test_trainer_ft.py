@@ -99,19 +99,11 @@ def test_gradient_compression_error_feedback():
 
     from jax.sharding import PartitionSpec as P
 
-    # jax >= 0.5 promotes shard_map to jax.shard_map (check_vma kwarg);
-    # earlier releases ship it under experimental (check_rep kwarg)
-    if hasattr(jax, "shard_map"):
-        smap, no_check = jax.shard_map, {"check_vma": False}
-    else:
-        from jax.experimental.shard_map import shard_map as smap
-        no_check = {"check_rep": False}
-
     def run(g, e):
-        return smap(
+        return jax.shard_map(
             lambda gg, ee: compress_psum(gg, ee, "x"),
             mesh=jax.make_mesh((1,), ("x",)),
-            in_specs=(P(), P()), out_specs=P(), **no_check)(g, e)
+            in_specs=(P(), P()), out_specs=P(), check_vma=False)(g, e)
 
     ghat, e2 = run(g, e)
     scale = float(jnp.max(jnp.abs(g["w"]))) / 127
