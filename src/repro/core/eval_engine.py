@@ -63,6 +63,8 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro import obs
+
 __all__ = ["PopulationEvalEngine", "PrefixEvalEngine", "ActivationStore",
            "DeviceScheduler", "DeviceStreams", "PrefixRef", "StackedView",
            "chunked_rows", "bucket_size", "pad_rows",
@@ -187,6 +189,13 @@ class DeviceStreams:
     def __exit__(self, *exc):
         for w in self._workers or ():
             w.shutdown()
+
+
+def _call(fn: Callable, *args):
+    """One jitted dispatch, spanned where it runs (a stream's worker
+    or the calling thread)."""
+    with obs.span("engine.call"):
+        return fn(*args)
 
 
 def bucket_size(n: int) -> int:
@@ -755,65 +764,66 @@ class PrefixEvalEngine:
            dispatch groups.  Compile keys number at most ``~2·L``
            (< the L·log2 L ladder bound).
         """
-        L = self.n_units
-        kids: dict[tuple, dict] = {(): {}}
-        for r in rows:
-            p = ()
-            for g in r:
-                kids.setdefault(p, {}).setdefault(g, None)
-                p += (g,)
-            kids.setdefault(p, {})
-        self.branch_nodes += sum(1 for c in kids.values() if len(c) >= 2)
+        with obs.span("engine.plan"):
+            L = self.n_units
+            kids: dict[tuple, dict] = {(): {}}
+            for r in rows:
+                p = ()
+                for g in r:
+                    kids.setdefault(p, {}).setdefault(g, None)
+                    p += (g,)
+                kids.setdefault(p, {})
+            self.branch_nodes += sum(1 for c in kids.values() if len(c) >= 2)
 
-        need: dict[tuple, None] = {}       # ordered set, parents first
-        hits: set = set()
-        for r in rows:
-            d = L - 1                      # deepest proper prefix to probe
-            while d > 0 and r[:d] not in self.store:
-                d -= 1
-            if d > 0 and r[:d] not in hits:
-                hits.add(r[:d])
-                self.prefix_hits += 1
-            for dd in range(d + 1, L):
-                need.setdefault(r[:dd])
-        need_children: dict[tuple, list] = {}
-        for p in need:
-            need_children.setdefault(p[:-1], []).append(p[-1])
+            need: dict[tuple, None] = {}       # ordered set, parents first
+            hits: set = set()
+            for r in rows:
+                d = L - 1                      # deepest proper prefix to probe
+                while d > 0 and r[:d] not in self.store:
+                    d -= 1
+                if d > 0 and r[:d] not in hits:
+                    hits.add(r[:d])
+                    self.prefix_hits += 1
+                for dd in range(d + 1, L):
+                    need.setdefault(r[:dd])
+            need_children: dict[tuple, list] = {}
+            for p in need:
+                need_children.setdefault(p[:-1], []).append(p[-1])
 
-        cut = set(self.shared_fields.values())
-        chains: list[tuple[tuple, list]] = []   # (parent_prefix, genes)
-        for p in need:                     # parents precede children
-            par = p[:-1]
-            if (par in need and len(need_children.get(par, ())) == 1
-                    and (len(par) - 1) not in cut):
-                continue                   # p extends its parent's chain
-            genes = [p[-1]]
-            cur = p
-            while True:
-                nc = need_children.get(cur, ())
-                if len(nc) != 1 or (len(cur) - 1) in cut:
-                    break
-                cur += (nc[0],)
-                genes.append(nc[0])
-            chains.append((par, genes))
-            self.max_chain = max(self.max_chain, len(genes))
-        # every row's final unit: its own length-1 chain/segment
-        finals = [(r[:L - 1], [r[L - 1]]) for r in rows]
-        self.chains += len(chains) + len(finals)
+            cut = set(self.shared_fields.values())
+            chains: list[tuple[tuple, list]] = []   # (parent_prefix, genes)
+            for p in need:                     # parents precede children
+                par = p[:-1]
+                if (par in need and len(need_children.get(par, ())) == 1
+                        and (len(par) - 1) not in cut):
+                    continue                   # p extends its parent's chain
+                genes = [p[-1]]
+                cur = p
+                while True:
+                    nc = need_children.get(cur, ())
+                    if len(nc) != 1 or (len(cur) - 1) in cut:
+                        break
+                    cur += (nc[0],)
+                    genes.append(nc[0])
+                chains.append((par, genes))
+                self.max_chain = max(self.max_chain, len(genes))
+            # every row's final unit: its own length-1 chain/segment
+            finals = [(r[:L - 1], [r[L - 1]]) for r in rows]
+            self.chains += len(chains) + len(finals)
 
-        segments: list[tuple[int, int, tuple, tuple]] = []
-        for par, genes in chains + finals:
-            s, m, off = len(par), len(genes), 0
-            while m:
-                ln = 1 << (m.bit_length() - 1)
-                at = s + off
-                if at:
-                    ln = min(ln, at & -at)     # buddy alignment
-                segments.append((at, ln, par + tuple(genes[:off]),
-                                 tuple(genes[off:off + ln])))
-                off += ln
-                m -= ln
-        return segments
+            segments: list[tuple[int, int, tuple, tuple]] = []
+            for par, genes in chains + finals:
+                s, m, off = len(par), len(genes), 0
+                while m:
+                    ln = 1 << (m.bit_length() - 1)
+                    at = s + off
+                    if at:
+                        ln = min(ln, at & -at)     # buddy alignment
+                    segments.append((at, ln, par + tuple(genes[:off]),
+                                     tuple(genes[off:off + ln])))
+                    off += ln
+                    m -= ln
+            return segments
 
     # -- storage / materialisation -------------------------------------------
     def _use_views(self) -> bool:
@@ -830,27 +840,31 @@ class PrefixEvalEngine:
         interning must rewrite fields."""
         import jax
 
-        j = 0
-        for batch, n in chunks:
-            rows = keys[j:j + n]
-            if self._use_views():
-                for r, key in enumerate(rows):
-                    self.store.put(key, StackedView(batch, r), pinned=pin)
-                self.views_stored += n
-            else:
-                for r, key in enumerate(rows):
-                    act = jax.tree.map(lambda a, r=r: a[r], batch.tree)
-                    self.store.put(key, self._intern(key, act), pinned=pin)
-            j += n
+        with obs.span("engine.store"):
+            j = 0
+            for batch, n in chunks:
+                rows = keys[j:j + n]
+                if self._use_views():
+                    for r, key in enumerate(rows):
+                        self.store.put(key, StackedView(batch, r),
+                                       pinned=pin)
+                    self.views_stored += n
+                else:
+                    for r, key in enumerate(rows):
+                        act = jax.tree.map(lambda a, r=r: a[r], batch.tree)
+                        self.store.put(key, self._intern(key, act),
+                                       pinned=pin)
+                j += n
 
     def _gather_final(self, pending: list):
         """The once-per-call gather: one host transfer per chunk."""
-        for keys, chunks in pending:
-            j = 0
-            for out, n in chunks:
-                for p, v in zip(keys[j:j + n], np.asarray(out)[:n]):
-                    self._cache[p] = float(v)
-                j += n
+        with obs.span("engine.gather"):
+            for keys, chunks in pending:
+                j = 0
+                for out, n in chunks:
+                    for p, v in zip(keys[j:j + n], np.asarray(out)[:n]):
+                        self._cache[p] = float(v)
+                    j += n
 
     def _intern(self, prefix: tuple, act):
         """Replace shared carry fields (deeper than their keying depth)
@@ -906,20 +920,22 @@ class PrefixEvalEngine:
         prefix (recursing up the chain as needed) and re-store it."""
         import jax
 
-        i = len(prefix) - 1
-        parents = None if i == 0 else [self._parent_for(prefix[:-1])]
-        devs = np.array([[prefix[-1]]], np.int64)
-        dev_idx = None if self._multi() is None else \
-            self._device_index(prefix)
-        outs, = self._dispatch_groups(self.unit_fns[i],
-                                      [(parents, devs, dev_idx)],
-                                      final=False, unit_axis=False)
-        batch, _ = outs[0]
-        act = jax.tree.map(lambda a: a[0], batch.tree)
-        self.unit_runs += 1
-        self.recomputes += 1
-        self.store.put(prefix, self._intern(prefix, act), pinned={prefix})
-        return act
+        with obs.span("engine.recompute"):
+            i = len(prefix) - 1
+            parents = None if i == 0 else [self._parent_for(prefix[:-1])]
+            devs = np.array([[prefix[-1]]], np.int64)
+            dev_idx = None if self._multi() is None else \
+                self._device_index(prefix)
+            outs, = self._dispatch_groups(self.unit_fns[i],
+                                          [(parents, devs, dev_idx)],
+                                          final=False, unit_axis=False)
+            batch, _ = outs[0]
+            act = jax.tree.map(lambda a: a[0], batch.tree)
+            self.unit_runs += 1
+            self.recomputes += 1
+            self.store.put(prefix, self._intern(prefix, act),
+                           pinned={prefix})
+            return act
 
     def _stack_chunk(self, parents: list, padded: int):
         """Assemble one dispatch chunk's stacked parent activations.
@@ -930,13 +946,15 @@ class PrefixEvalEngine:
         import jax.numpy as jnp
 
         chunk = list(parents) + [parents[-1]] * (padded - len(parents))
-        if (len(chunk) > 1
-                and all(isinstance(p, StackedView) for p in chunk)
-                and all(p.batch is chunk[0].batch for p in chunk)):
-            idx = np.array([p.index for p in chunk], np.int32)
-            return jax.tree.map(lambda a: a[idx], chunk[0].batch.tree)
-        mats = [self._materialize(p) for p in chunk]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *mats)
+        gather = (len(chunk) > 1
+                  and all(isinstance(p, StackedView) for p in chunk)
+                  and all(p.batch is chunk[0].batch for p in chunk))
+        with obs.span("engine.stack", path="gather" if gather else "stack"):
+            if gather:
+                idx = np.array([p.index for p in chunk], np.int32)
+                return jax.tree.map(lambda a: a[idx], chunk[0].batch.tree)
+            mats = [self._materialize(p) for p in chunk]
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *mats)
 
     def _dispatch_groups(self, fn: Callable, jobs: list, final: bool,
                          unit_axis: bool = True) -> list:
@@ -982,26 +1000,28 @@ class PrefixEvalEngine:
                 for j, (parents, genes, dev_idx) in enumerate(jobs):
                     if k >= len(plans[j]):
                         continue
-                    start, stop, padded = plans[j][k]
-                    device = None if dev_idx is None \
-                        else self.scheduler.devices[dev_idx]
-                    g = np.asarray(pad_rows(genes[start:stop], padded),
-                                   np.int32)
-                    g_c = DeviceScheduler.put(g if unit_axis else g[:, 0],
-                                              device)
-                    acts = None if parents is None else \
-                        self._stack_chunk(parents[start:stop], padded)
+                    with obs.span("engine.dispatch"):
+                        start, stop, padded = plans[j][k]
+                        device = None if dev_idx is None \
+                            else self.scheduler.devices[dev_idx]
+                        g = np.asarray(pad_rows(genes[start:stop], padded),
+                                       np.int32)
+                        g_c = DeviceScheduler.put(
+                            g if unit_axis else g[:, 0], device)
+                        acts = None if parents is None else \
+                            self._stack_chunk(parents[start:stop], padded)
+                        if flight[j] is not None:
+                            land(j)
+                        flight[j] = (streams.submit(j, _call, fn, acts, g_c),
+                                     stop - start, padded)
+                        self.dispatches += 1
+                        if dev_idx is not None:
+                            self.device_dispatches[dev_idx] = \
+                                self.device_dispatches.get(dev_idx, 0) + 1
+            with obs.span("engine.dispatch"):
+                for j in range(len(jobs)):
                     if flight[j] is not None:
                         land(j)
-                    flight[j] = (streams.submit(j, fn, acts, g_c),
-                                 stop - start, padded)
-                    self.dispatches += 1
-                    if dev_idx is not None:
-                        self.device_dispatches[dev_idx] = \
-                            self.device_dispatches.get(dev_idx, 0) + 1
-            for j in range(len(jobs)):
-                if flight[j] is not None:
-                    land(j)
         return outs
 
 

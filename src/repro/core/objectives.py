@@ -98,6 +98,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.costmodel import CostModel
 from repro.core.eval_engine import (DeviceScheduler, PopulationEvalEngine,
                                     PrefixEvalEngine, auto_eval_batch_size,
@@ -417,10 +418,12 @@ class InferenceAccuracyEvaluator:
                 return jnp.mean((pred == labels).astype(jnp.float32))
             return x
 
+        name = f"engine_seg_{start}_{length}"
         if start == 0:
-            batched = jax.jit(jax.vmap(lambda g: seg(x0, g)))
+            batched = jax.jit(jax.vmap(obs.named(lambda g: seg(x0, g),
+                                                 name)))
             return lambda acts, genes, b=batched: b(genes)
-        batched = jax.jit(jax.vmap(seg))
+        batched = jax.jit(jax.vmap(obs.named(seg, name)))
         return lambda acts, genes, b=batched: b(acts, genes)
 
     def _build_segment_fn_pallas(self, start: int, length: int) -> Callable:
@@ -453,13 +456,15 @@ class InferenceAccuracyEvaluator:
                 return jnp.mean((pred == labels).astype(jnp.float32))
             return x
 
+        name = f"engine_seg_{start}_{length}"
         if start == 0:
             batched = jax.jit(jax.vmap(
-                lambda g, w, a, s: seg(x0, g, w, a, s),
+                obs.named(lambda g, w, a, s: seg(x0, g, w, a, s), name),
                 in_axes=(0, None, None, None)))
             return lambda acts, genes, b=batched, r=ref: \
                 b(genes, *_pallas_env_args(r))
-        batched = jax.jit(jax.vmap(seg, in_axes=(0, 0, None, None, None)))
+        batched = jax.jit(jax.vmap(obs.named(seg, name),
+                                   in_axes=(0, 0, None, None, None)))
         return lambda acts, genes, b=batched, r=ref: \
             b(acts, genes, *_pallas_env_args(r))
 
@@ -499,11 +504,13 @@ class InferenceAccuracyEvaluator:
                     logits = unit(act, d)
                     pred = jnp.argmax(logits, axis=-1)
                     return jnp.mean((pred == labels).astype(jnp.float32))
+            name = f"engine_unit_{i}"
             if i == 0:
-                batched = jax.jit(jax.vmap(lambda d, f=one: f(x, d)))
+                batched = jax.jit(jax.vmap(obs.named(
+                    lambda d, f=one: f(x, d), name)))
                 fns.append(lambda acts, devs, b=batched: b(devs))
             else:
-                batched = jax.jit(jax.vmap(one))
+                batched = jax.jit(jax.vmap(obs.named(one, name)))
                 fns.append(lambda acts, devs, b=batched: b(acts, devs))
         return fns
 
@@ -534,15 +541,17 @@ class InferenceAccuracyEvaluator:
                     logits = unit(act, d, w_dev, a_dev, sd)
                     pred = jnp.argmax(logits, axis=-1)
                     return jnp.mean((pred == labels).astype(jnp.float32))
+            name = f"engine_unit_{i}"
             if i == 0:
                 batched = jax.jit(jax.vmap(
-                    lambda d, w, a, s, f=one: f(x, d, w, a, s),
+                    obs.named(lambda d, w, a, s, f=one: f(x, d, w, a, s),
+                              name),
                     in_axes=(0, None, None, None)))
                 fns.append(lambda acts, devs, b=batched, r=ref:
                            b(devs, *_pallas_env_args(r)))
             else:
                 batched = jax.jit(jax.vmap(
-                    one, in_axes=(0, 0, None, None, None)))
+                    obs.named(one, name), in_axes=(0, 0, None, None, None)))
                 fns.append(lambda acts, devs, b=batched, r=ref:
                            b(acts, devs, *_pallas_env_args(r)))
         return fns
@@ -1056,18 +1065,21 @@ class ObjectiveFn:
         if (self.eval_batch_size is not None
                 and hasattr(self.acc_evaluator, "eval_batch_size")):
             self.acc_evaluator.eval_batch_size = self.eval_batch_size
+        self._calls = 0
 
     @property
     def n_objectives(self) -> int:
         return 2 if self.acc_evaluator is None else 3
 
     def __call__(self, P: np.ndarray) -> np.ndarray:
-        lat = self.cost_model.latency(P) * self.latency_weight
-        en = self.cost_model.energy_of(P) * self.energy_weight
-        if self.acc_evaluator is None:
-            return np.stack([lat, en], axis=1)
-        dacc = self.acc_evaluator.delta_acc(P)
-        return np.stack([lat, en, dacc], axis=1)
+        self._calls += 1
+        with obs.span("objective", call=self._calls, rows=len(P)):
+            lat = self.cost_model.latency(P) * self.latency_weight
+            en = self.cost_model.energy_of(P) * self.energy_weight
+            if self.acc_evaluator is None:
+                return np.stack([lat, en], axis=1)
+            dacc = self.acc_evaluator.delta_acc(P)
+            return np.stack([lat, en, dacc], axis=1)
 
     def violation(self, P: np.ndarray) -> np.ndarray:
         return self.cost_model.violation(P)
